@@ -12,6 +12,8 @@ def main() -> None:
                             bench_migration, bench_replication,
                             bench_runtime_overhead, bench_speculation,
                             bench_validation)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for mod in (bench_migration, bench_runtime_overhead, bench_edge_cloud,

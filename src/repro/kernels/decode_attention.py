@@ -24,9 +24,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):     # jax < 0.5 spelling
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 

@@ -12,6 +12,10 @@ FLOPs match the exact-causal CPU path (models/attention.flash_causal).
 GQA maps query head h to kv head h*KV//H in the BlockSpec index maps --
 no KV replication in VMEM.
 
+Any length is accepted: a sequence longer than its block is zero-padded
+up to a block multiple, padded keys are masked out (``kpos < kv_len``)
+and padded query rows are sliced off the result.
+
 Validated in interpret mode against kernels/ref.py on CPU
 (tests/test_kernels.py sweeps shapes & dtypes).
 """
@@ -24,12 +28,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale, causal, window, softcap, block_q, block_k, nk):
+            scale, causal, window, softcap, block_q, block_k, nk, kv_len):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -61,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             s = jnp.tanh(s / softcap) * softcap
         qpos = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kpos = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = jnp.bool_(True)
+        mask = kpos < kv_len                         # padded keys
         if causal:
             mask = jnp.logical_and(mask, kpos <= qpos)
         if window:
@@ -94,22 +99,24 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     G = H // KV
     block_q = min(block_q, Sq)
     block_k = min(block_k, Skv)
-    assert Sq % block_q == 0 and Skv % block_k == 0
-    nq, nk = Sq // block_q, Skv // block_k
+    nq, nk = -(-Sq // block_q), -(-Skv // block_k)
     scale = D ** -0.5
 
-    # (B, S, H, D) -> (B, H, S, D) so the lane dim is D
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    # (B, S, H, D) -> (B, H, S, D) so the lane dim is D; sequences
+    # padded up to a whole number of blocks
+    def blocked(x, n):
+        x = x.transpose(0, 2, 1, 3)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
+
+    qt = blocked(q, nq * block_q)
+    kt = blocked(k, nk * block_k)
+    vt = blocked(v, nk * block_k)
 
     grid = (B, H, nq, nk)
     kern = functools.partial(
         _kernel, scale=scale, causal=causal, window=window,
-        softcap=softcap, block_q=block_q, block_k=block_k, nk=nk)
-    from jax.experimental.pallas import tpu as pltpu
-    if not hasattr(pltpu, "CompilerParams"):     # jax < 0.5 spelling
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
+        softcap=softcap, block_q=block_q, block_k=block_k, nk=nk,
+        kv_len=Skv)
     out = pl.pallas_call(
         kern,
         grid=grid,
@@ -123,7 +130,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D),
                                lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, nq * block_q, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # m (lane-bcast)
             pltpu.VMEM((block_q, 128), jnp.float32),   # l
@@ -134,4 +141,4 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                                  "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

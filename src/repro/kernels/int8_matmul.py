@@ -17,9 +17,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):     # jax < 0.5 spelling
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, w_ref, s_ref, o_ref, acc_scr, *, nk):
     ki = pl.program_id(2)

@@ -8,7 +8,8 @@ jnp oracle (``ref.py``).  Dispatch policy:
                           ``models.attention`` (same FLOP profile as the
                           kernel, so §Roofline derived from the CPU-
                           compiled HLO is faithful)
-  * ``REPRO_FORCE_REF=1`` or ``set_backend("ref")`` -> oracle (tests)
+  * ``set_backend("ref")`` -> oracle (tests, the chip smoke's
+                          reference run)
 
 ``interpret=True`` Pallas execution is reachable via
 ``set_backend("interpret")`` -- used by the kernel test sweeps on CPU.
@@ -16,10 +17,10 @@ jnp oracle (``ref.py``).  Dispatch policy:
 
 from __future__ import annotations
 
-import os
-
 import jax
+from jax.sharding import PartitionSpec as P
 
+from repro import sharding as shd
 from repro.models import attention as attn_ref
 
 _BACKEND_OVERRIDE: str | None = None  # None | "ref" | "pallas" | "interpret"
@@ -33,8 +34,6 @@ def set_backend(name: str | None):
 def backend() -> str:
     if _BACKEND_OVERRIDE:
         return _BACKEND_OVERRIDE
-    if os.environ.get("REPRO_FORCE_REF"):
-        return "ref"
     platform = jax.default_backend()
     return "pallas" if platform == "tpu" else "jnp_block"
 
@@ -51,12 +50,41 @@ def _interpret() -> bool:
 # attention
 # --------------------------------------------------------------------------
 
-def attention_causal(q, k, v, *, softcap=0.0, block=512):
+def _per_device(kernel, q, k, v, mesh, rules):
+    """Run a Pallas attention kernel on each device's shard of (q, k, v).
+
+    Mosaic kernels cannot be partitioned automatically, so on a mesh the
+    call goes inside a shard_map whose specs follow the activations'
+    logical layout.  Heads stay split only where query and kv heads are
+    split alike: the kernel maps local query head h to kv head h // G."""
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v)
+
+    def spec(x, heads):
+        s = tuple(shd.resolve(("batch", None, heads, None), mesh, x.shape,
+                              rules))
+        return s + (None,) * (4 - len(s))
+
+    qs, ks = spec(q, "act_heads"), spec(k, "act_kv_heads")
+    if qs[2] != ks[2]:
+        qs, ks = qs[:1] + (None,) * 3, ks[:1] + (None,) * 3
+    return shd.shard_map(kernel, mesh=mesh, in_specs=(P(*qs), P(*ks), P(*ks)),
+                         out_specs=P(*qs))(q, k, v)
+
+
+def _flash(q, k, v, mesh, rules, **kw):
+    from repro.kernels import flash_attention as fa
+    return _per_device(
+        lambda q, k, v: fa.flash_attention(q, k, v, interpret=_interpret(),
+                                           **kw),
+        q, k, v, mesh, rules)
+
+
+def attention_causal(q, k, v, *, softcap=0.0, block=512, mesh=None,
+                     rules=None):
     if _pallas_ok():
-        from repro.kernels import flash_attention as fa
-        return fa.flash_attention(q, k, v, causal=True, window=0,
-                                  softcap=softcap,
-                                  interpret=_interpret())
+        return _flash(q, k, v, mesh, rules, causal=True, window=0,
+                      softcap=softcap)
     if backend() == "ref":
         return attn_ref.reference_attention(q, k, v, causal=True,
                                             softcap=softcap)
@@ -64,12 +92,10 @@ def attention_causal(q, k, v, *, softcap=0.0, block=512):
 
 
 def attention_windowed(q, k, v, *, window, softcap=0.0, block=512,
-                       q_offset=0):
+                       q_offset=0, mesh=None, rules=None):
     if _pallas_ok():
-        from repro.kernels import flash_attention as fa
-        return fa.flash_attention(q, k, v, causal=True, window=window,
-                                  softcap=softcap,
-                                  interpret=_interpret())
+        return _flash(q, k, v, mesh, rules, causal=True, window=window,
+                      softcap=softcap)
     if backend() == "ref":
         return attn_ref.reference_attention(q, k, v, causal=True,
                                             window=window, softcap=softcap,
@@ -78,12 +104,11 @@ def attention_windowed(q, k, v, *, window, softcap=0.0, block=512,
                                    block=block, q_offset=q_offset)
 
 
-def attention_full(q, k, v, *, softcap=0.0, block=512, kv_len=None):
+def attention_full(q, k, v, *, softcap=0.0, block=512, kv_len=None,
+                   mesh=None, rules=None):
     if _pallas_ok():
-        from repro.kernels import flash_attention as fa
-        return fa.flash_attention(q, k, v, causal=False, window=0,
-                                  softcap=softcap,
-                                  interpret=_interpret())
+        return _flash(q, k, v, mesh, rules, causal=False, window=0,
+                      softcap=softcap)
     if backend() == "ref":
         return attn_ref.reference_attention(q, k, v, causal=False,
                                             softcap=softcap, kv_len=kv_len)

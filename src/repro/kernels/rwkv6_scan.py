@@ -22,9 +22,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):     # jax < 0.5 spelling
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref,
             s_scr, *, nc, chunk):

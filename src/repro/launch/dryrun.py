@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# CPU only, for this process and the per-cell children it starts: a
+# parent holding a TPU would leave its children no chip to start on
+os.environ["JAX_PLATFORMS"] = "cpu"
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 on the production meshes, record memory/cost analyses + roofline terms.
 
@@ -119,7 +122,8 @@ def main():
                    "--arch", arch, "--shape", shape, "--mesh", meshk,
                    "--out", args.out]
             print(">>", " ".join(cmd), flush=True)
-            r = subprocess.run(cmd, timeout=args.timeout)
+            r = subprocess.run(cmd, timeout=args.timeout,
+                               env={**os.environ, "JAX_PLATFORMS": "cpu"})
             if r.returncode != 0:
                 failures.append((arch, shape, meshk))
                 print("!! FAILED", arch, shape, meshk, flush=True)

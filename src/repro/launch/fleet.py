@@ -74,6 +74,9 @@ Flags
   --max-len N            per-slot context budget (default 128)
   --requests N           synthetic mixed-sensitivity request count
   --max-new N            tokens generated per request (default 16)
+  --prompt-lens LIST     comma list of prompt lengths cycled across the
+                         synthetic requests, a tenant's prefix included
+                         (default: 8 tokens after the tenant prefix)
   --temperature F        sampling temperature for odd-numbered requests
                          (even ones stay greedy: mixed-policy batches)
   --priorities LIST      comma list of ints cycled across the synthetic
@@ -203,7 +206,9 @@ def tier_model(cfg, params, tier, seed: int):
     return cfg, params
 
 
-def main():
+def main(argv: list[str] | None = None):
+    """Serve the synthetic request stream; returns the FleetController
+    (``argv`` defaults to the command line)."""
     ap = argparse.ArgumentParser(
         description="serve a request stream over a heterogeneous fleet")
     ap.add_argument("--arch", default="llama-1.5b")
@@ -216,6 +221,7 @@ def main():
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--prompt-lens", default=None, metavar="LIST")
     ap.add_argument("--temperature", type=float, default=0.8)
     ap.add_argument("--priorities", default="0", metavar="LIST")
     ap.add_argument("--deadline-s", type=float, default=None)
@@ -270,10 +276,13 @@ def main():
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write a Prometheus text exposition of the "
                          "fleet metrics registry here")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     import jax
     import numpy as np
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs import get
     from repro.configs.tiny import make_tiny
@@ -375,10 +384,17 @@ def main():
         for t in range(args.tenants):
             bases[f"t{t}"] = rng.integers(5, cfg.vocab_size,
                                           2 * args.page_size)
+    lens = [int(n) for n in args.prompt_lens.split(",")] \
+        if args.prompt_lens else []
     pending = []
     for i in range(args.requests):
         tenant = f"t{i % args.tenants}" if args.tenants else ""
-        tail = rng.integers(5, cfg.vocab_size, 8)
+        base = len(bases[tenant]) if tenant else 0
+        n_tail = lens[i % len(lens)] - base if lens else 8
+        if n_tail < 1:
+            ap.error(f"--prompt-lens: request r{i} needs more than its "
+                     f"{base}-token tenant prefix")
+        tail = rng.integers(5, cfg.vocab_size, n_tail)
         prompt = np.concatenate([bases[tenant], tail]) if tenant else tail
         pending.append(
             RequestSpec(rid=f"r{i}", prompt=prompt,
@@ -429,7 +445,7 @@ def main():
             with open(args.metrics_out, "w") as f:
                 f.write(fleet.telemetry.prometheus_text())
             print(f"metrics: {args.metrics_out}")
-        return
+        return fleet
 
     fail = parse_event(args.fail)
     drain = parse_event(args.drain)
@@ -534,6 +550,7 @@ def main():
         with open(args.metrics_out, "w") as f:
             f.write(fleet.telemetry.prometheus_text())
         print(f"metrics: {args.metrics_out}")
+    return fleet
 
 
 if __name__ == "__main__":

@@ -13,6 +13,13 @@ jax device state (the dry-run pins XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int) -> tuple:
+    """Auto axes: ``jax.make_mesh`` defaults to Explicit axes, which
+    ``with_sharding_constraint`` (``sharding.constrain``) refuses."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,16 +29,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = math.prod(shape)
     devs = jax.devices()
     if len(devs) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)))
     assert len(devs) >= n, (
         f"need {n} devices for mesh {shape}; have {len(devs)} "
         "(dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
         "=512 before any jax import)")
     from jax.experimental import mesh_utils
     dm = mesh_utils.create_device_mesh(shape, devices=devs[:n])
-    return jax.sharding.Mesh(dm, axes)
+    return jax.sharding.Mesh(dm, axes, axis_types=_auto(len(shape)))
 
 
 def make_debug_mesh(model: int = 1, data: int = 1):
     """Small mesh over however many (CPU) devices tests spawned."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))

@@ -27,6 +27,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get
     from repro.configs.tiny import make_tiny
     from repro.core.daemon import PrivacyAwareDaemon

@@ -14,7 +14,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import sharding as shd
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.configs.inputs import batch_specs, cache_specs_abstract
-from repro.models import schema
 from repro.models.init import abstract_params
 from repro.models.model import cache_specs, forward
 from repro.optim.adamw import AdamWConfig
@@ -23,12 +22,6 @@ from repro.training.train import TrainConfig, train_step
 
 def merged_rules(cfg: ModelConfig, shape: ShapeSpec) -> dict:
     return {**cfg.overrides, **shape.overrides}
-
-
-def param_shardings(cfg: ModelConfig, mesh, rules):
-    tree = schema.model_schema(cfg)
-    specs = shd.tree_specs(tree, mesh, rules)
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
 
 
 def batch_shardings(batch, cfg, mesh, rules):
@@ -77,7 +70,7 @@ def build_train(cfg: ModelConfig, shape: ShapeSpec, mesh,
     params_abs = abstract_params(cfg)
     opt_abs = abstract_opt(params_abs)
     batch = batch_specs(cfg, shape)
-    pshard = param_shardings(cfg, mesh, rules)
+    pshard = shd.param_shardings(cfg, mesh, rules)
     in_shardings = (pshard, opt_shardings(pshard, mesh),
                     batch_shardings(batch, cfg, mesh, rules))
     out_shardings = (pshard, opt_shardings(pshard, mesh),
@@ -98,7 +91,7 @@ def build_prefill(cfg: ModelConfig, shape: ShapeSpec, mesh):
     params_abs = abstract_params(cfg)
     batch = batch_specs(cfg, shape)
     caches = cache_specs_abstract(cfg, shape)
-    pshard = param_shardings(cfg, mesh, rules)
+    pshard = shd.param_shardings(cfg, mesh, rules)
     cshard = cache_shardings(caches, mesh, rules)
     in_shardings = (pshard,
                     batch_shardings(batch, cfg, mesh, rules), cshard)
@@ -124,7 +117,7 @@ def build_decode(cfg: ModelConfig, shape: ShapeSpec, mesh):
     params_abs = abstract_params(cfg)
     batch = batch_specs(cfg, shape)
     caches = cache_specs_abstract(cfg, shape)
-    pshard = param_shardings(cfg, mesh, rules)
+    pshard = shd.param_shardings(cfg, mesh, rules)
     cshard = cache_shardings(caches, mesh, rules)
     in_shardings = (pshard,
                     batch_shardings(batch, cfg, mesh, rules), cshard)

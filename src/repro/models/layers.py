@@ -193,15 +193,13 @@ def attention_apply(p, x, *, cfg: ModelConfig, lspec: LayerSpec, mode: str,
                                        softcap=cfg.attn_softcap)
     else:
         from repro.kernels import ops as kops
-        if cross:
-            o = kops.attention_full(q, k, v, softcap=cfg.attn_softcap)
-        elif not causal:  # encoder self-attention
-            o = kops.attention_full(q, k, v, softcap=cfg.attn_softcap)
+        on = dict(softcap=cfg.attn_softcap, mesh=mesh, rules=rules)
+        if cross or not causal:  # cross / encoder self-attention
+            o = kops.attention_full(q, k, v, **on)
         elif window:
-            o = kops.attention_windowed(q, k, v, window=window,
-                                        softcap=cfg.attn_softcap)
+            o = kops.attention_windowed(q, k, v, window=window, **on)
         else:
-            o = kops.attention_causal(q, k, v, softcap=cfg.attn_softcap)
+            o = kops.attention_causal(q, k, v, **on)
         new_cache = None
         if mode == "prefill" and cache is not None:
             if cross:

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import sharding as shd
 from repro.configs.base import ModelConfig
 from repro.models.model import forward, make_cache, vocab_mask_logits
 from repro.serving.program_cache import get_programs
@@ -173,6 +174,11 @@ class Engine:
                  max_len: int = 256, mesh=None, rules=None, seed: int = 0,
                  profile_hook=None):
         self.cfg = cfg
+        if mesh is not None:
+            # weights are laid out by the mesh's rules (e.g. heads over
+            # "model"), never left whole on the default device
+            params = jax.device_put(
+                params, shd.param_shardings(cfg, mesh, rules))
         self.params = params
         self.slots = slots
         self.max_len = max_len

@@ -52,15 +52,9 @@ DEFAULT_RULES: dict[str, tuple[str, ...] | str | None] = {
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: new jax has it at top level
-    with ``check_vma``; older jax spells it jax.experimental.shard_map
-    with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def _axis_size(mesh: Mesh, axes: tuple[str, ...]) -> int:
@@ -134,6 +128,14 @@ def tree_specs(schema_tree, mesh: Mesh, overrides=None):
 
     return jax.tree.map(leaf, schema_tree,
                         is_leaf=lambda x: isinstance(x, ParamDef))
+
+
+def param_shardings(cfg, mesh: Mesh, rules=None):
+    """``NamedSharding`` pytree placing a model's parameters on ``mesh``."""
+    from repro.models.schema import model_schema  # local: avoids a cycle
+
+    specs = tree_specs(model_schema(cfg), mesh, rules)
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
 
 
 def constrain(x, mesh: Mesh, logical: Sequence[str | None], overrides=None):

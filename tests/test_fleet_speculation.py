@@ -151,11 +151,16 @@ def test_non_greedy_requests_stay_local():
 
 def test_validator_halts_speculative_request_mid_stream():
     """core/validation runs on the committed stream in parallel with the
-    next draft round and can stop the request before max_new."""
+    next draft round and can stop the request before max_new.  The
+    marker is a token this fleet itself commits early in an unvalidated
+    run, so the trigger does not depend on what a random model emits."""
+    prompt = np.asarray([12, 14, 16, 18, 12, 14, 16, 18])
+    clean = mk_spec_fleet().run(
+        [Request("bad", prompt, max_new_tokens=16)])["bad"]
+    assert len(clean) == 16
     fleet = mk_spec_fleet(validators=[
-        MarkerValidator("harmful_content", "harmful", range(10, 20))])
-    bad = Request("bad", np.asarray([12, 14, 16, 18, 12, 14, 16, 18]),
-                  max_new_tokens=16)
+        MarkerValidator("harmful_content", "harmful", {clean[1]})])
+    bad = Request("bad", prompt, max_new_tokens=16)
     outs = fleet.run([bad])
     st = fleet.spec_controllers["edge"].stats
     assert st.interventions == 1

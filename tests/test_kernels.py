@@ -24,6 +24,7 @@ def tol(dtype):
     (1, 128, 4, 4, 64),    # MHA
     (2, 256, 8, 2, 64),    # GQA 4:1
     (1, 128, 4, 1, 128),   # MQA
+    (1, 200, 8, 2, 64),    # GQA, length padded up to a block multiple
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("mode", ["causal", "window", "full", "softcap"])
@@ -44,6 +45,24 @@ def test_flash_attention_sweep(B, S, H, KV, D, dtype, mode):
     err = float(jnp.abs(o.astype(jnp.float32)
                         - oref.astype(jnp.float32)).max())
     assert err < tol(dtype), (mode, err)
+
+
+@pytest.mark.parametrize("Sq,H,KV", [
+    (600, 4, 4),           # one 512 block plus a padded one
+    (520, 8, 2),           # GQA 4:1, padded to 1024
+])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_padded_lengths(Sq, H, KV, causal):
+    """Lengths over the default 512 block that are not a multiple of it:
+    padded keys must be masked and padded query rows dropped."""
+    D = 64
+    q = jnp.asarray(RNG.standard_normal((1, Sq, H, D)), jnp.float32)
+    k = jnp.asarray(RNG.standard_normal((1, Sq, KV, D)), jnp.float32)
+    v = jnp.asarray(RNG.standard_normal((1, Sq, KV, D)), jnp.float32)
+    o = flash_attention(q, k, v, causal=causal, interpret=True)
+    oref = ref.reference_attention(q, k, v, causal=causal)
+    assert o.shape == oref.shape
+    assert float(jnp.abs(o - oref).max()) < 2e-5
 
 
 @pytest.mark.parametrize("Sc,fill,window", [

@@ -138,7 +138,7 @@ def test_cross_mesh_migration_resharding():
     run_multidevice("""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from jax.tree_util import tree_flatten_with_path, keystr
 from repro.configs import get
 from repro.configs.tiny import make_tiny
@@ -151,8 +151,10 @@ from repro.models.model import cache_specs
 cfg = make_tiny(get('llama-1.5b'))
 params = init_params(cfg, jax.random.key(0))
 
-mesh_a = jax.make_mesh((1, 4), ('data', 'model'))
-mesh_b = jax.make_mesh((4, 1), ('data', 'model'))
+mesh_a = jax.make_mesh((1, 4), ('data', 'model'),
+                       axis_types=(AxisType.Auto,) * 2)
+mesh_b = jax.make_mesh((4, 1), ('data', 'model'),
+                       axis_types=(AxisType.Auto,) * 2)
 
 eng = Engine(cfg, params, slots=4, max_len=64, seed=3, mesh=mesh_a)
 req = Request('r0', np.arange(6), max_new_tokens=10)

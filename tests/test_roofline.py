@@ -64,9 +64,10 @@ def test_analyzer_counts_collectives_with_trips():
     from tests.helpers import run_multidevice
     run_multidevice("""
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import analyze
-mesh = jax.make_mesh((4,), ('model',))
+mesh = jax.make_mesh((4,), ('model',),
+                     axis_types=(AxisType.Auto,))
 def f(x, w):
     def body(c, _):
         h = c @ w                      # w sharded on contraction: psum

@@ -71,6 +71,7 @@ def test_moe_ep_equals_dense_oracle():
     real multi-device mesh."""
     run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.configs import get
 from repro.configs.tiny import make_tiny
 from repro.models.init import init_params
@@ -89,7 +90,8 @@ for g in params['blocks']:
             p = jax.tree.map(lambda a: a[0], lp['moe'])
 if p is None: raise SystemExit('no moe layer')
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 
 # a2a mode: tokens divide the full mesh
@@ -117,7 +119,7 @@ def test_pjit_train_step_on_mesh_matches_single_device():
     """One train step under a 2x2 mesh == the same step on 1 device."""
     run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import get, SHAPES
 from repro.configs.tiny import make_tiny
 from repro.models.init import init_params
@@ -135,7 +137,8 @@ batch = {'tokens': jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 32)),
 
 p1, o1, m1 = train_step(params, opt, batch, cfg=cfg, tcfg=tcfg)
 
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+mesh = jax.make_mesh((2, 2), ('data', 'model'),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = {}
 with mesh:
     p2, o2, m2 = jax.jit(
@@ -148,4 +151,41 @@ for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
     d = float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
     assert d < 1e-3, d
 print('pjit train parity OK')
+""", devices=4)
+
+
+def test_pallas_attention_on_mesh_matches_single_device():
+    """Pallas kernels cannot be partitioned automatically, so on a mesh
+    they run per device inside a shard_map: the prefill forward (kernels
+    in interpret mode) and the weights placed by an Engine on the mesh
+    must match the one-device results."""
+    run_multidevice("""
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import get
+from repro.configs.tiny import make_tiny
+from repro.kernels import ops
+from repro.launch.mesh import make_debug_mesh
+from repro.models.init import init_params
+from repro.models.model import forward
+from repro.serving.engine import Engine
+
+cfg = make_tiny(get('llama-1.5b')).replace(dtype='float32')
+params = init_params(cfg, jax.random.key(0))
+toks = jnp.asarray(np.random.default_rng(0).integers(0, 500, (2, 40)),
+                   jnp.int32)
+ops.set_backend('interpret')
+run = lambda mesh: jax.jit(lambda p, t: forward(
+    p, {'tokens': t}, cfg=cfg, mode='train', mesh=mesh)[0])
+ref = run(None)(params, toks)
+# (1, 4): query heads split, kv heads (2) do not divide -> heads whole;
+# (2, 2): both head counts split alike; (4, 1): batch split only
+for data, model in [(1, 4), (2, 2), (4, 1)]:
+    mesh = make_debug_mesh(model=model, data=data)
+    eng = Engine(cfg, params, slots=2, max_len=64, mesh=mesh)
+    w = eng.params['blocks'][0][0]['attn']['wq']
+    assert w.sharding.mesh.shape == mesh.shape, w.sharding
+    err = float(jnp.abs(run(mesh)(eng.params, toks) - ref).max())
+    print((data, model), err)
+    assert err < 1e-4, ((data, model), err)
+print('sharded pallas attention OK')
 """, devices=4)
