@@ -44,7 +44,7 @@ from repro.fleet.router import Router
 from repro.fleet.speculative import SpeculativeTierController
 from repro.fleet.telemetry import FleetTelemetry, QualityEvent
 from repro.fleet.tracing import Tracer
-from repro.serving.engine import Engine, Request
+from repro.serving.engine import Engine, Request, span
 
 
 @dataclass
@@ -587,19 +587,29 @@ class FleetController:
         # dispatch targets
         handles = [h for h in self.handles.values()
                    if h.healthy and h.spec_role != "verify"]
-        for item in self.queue.ordered(now=now,
-                                       aging_rate=self.aging_rate):
-            slack = None if item.deadline is None else item.deadline - now
-            if item.parked:
-                self._dispatch_parked(item, handles, slack, now)
-            else:
-                self._dispatch_fresh(item, handles, slack, now)
+        with span("fleet.dispatch"):
+            for item in self.queue.ordered(now=now,
+                                           aging_rate=self.aging_rate):
+                slack = (None if item.deadline is None
+                         else item.deadline - now)
+                if item.parked:
+                    self._dispatch_parked(item, handles, slack, now)
+                else:
+                    self._dispatch_fresh(item, handles, slack, now)
 
     # -- the fleet step ----------------------------------------------------------
     def step(self) -> dict[str, int]:
         """Autoscale, dispatch, advance every healthy engine one decode
         step, retire completions, shadow-checkpoint.  Returns
-        {rid: token} emitted."""
+        {rid: token} emitted.
+
+        The step is a ``fleet.step`` span on the profiler's timeline
+        whose ``clock`` stat is the fleet clock at entry: it places the
+        fleet clock's times (tickets, the tracer) on that timeline."""
+        with span("fleet.step", clock=self.clock):
+            return self._step()
+
+    def _step(self) -> dict[str, int]:
         if self.autoscaler is not None:
             # before dispatch: a spawn decision serves THIS step's
             # backlog, and a retire decision's displaced slots re-place

@@ -37,7 +37,6 @@ without cycles.
 
 from __future__ import annotations
 
-import hashlib
 import json
 import math
 import threading
@@ -668,74 +667,3 @@ class Tracer:
     def export_chrome(self, path: str):
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f)
-
-    @_locked
-    def otlp_trace(self) -> dict:
-        """OTLP/JSON ``ExportTraceServiceRequest`` (the dict;
-        ``export_otlp`` writes it) -- the spans in the standard
-        OpenTelemetry wire shape, ingestible by any OTLP-JSON collector.
-
-        Ids: OTLP wants 16-byte trace ids and 8-byte span ids in hex.
-        Trace ids here are strings ("r3", "engine:edge"), so they are
-        hashed to 32 hex chars (stable across exports); span ids are the
-        tracer's integer ids, zero-padded to 16.  Timestamps are
-        *run-relative* nanoseconds (the fleet clock is injectable and
-        often starts at 0 in tests/benches): subtract nothing, compare
-        within one export."""
-        def trace_hex(tid: str) -> str:
-            return hashlib.blake2b(tid.encode(),
-                                   digest_size=16).hexdigest()
-
-        def span_hex(sid: int) -> str:
-            return f"{sid & (2 ** 64 - 1):016x}"
-
-        def attr(k, v):
-            if isinstance(v, bool):
-                val = {"boolValue": v}
-            elif isinstance(v, int):
-                val = {"intValue": str(v)}       # OTLP JSON: int64 as str
-            elif isinstance(v, float):
-                val = {"doubleValue": v}
-            else:
-                val = {"stringValue": str(v)}
-            return {"key": k, "value": val}
-
-        def nanos(t: float) -> str:
-            return str(max(int(round((t - self._t0) * 1e9)), 0))
-
-        now = self._clock()
-        otlp_spans = []
-        for sp in self.spans:
-            t_end = sp.t_end if sp.t_end is not None else now
-            attrs = [attr("kind", sp.kind)]
-            if sp.engine:
-                attrs.append(attr("engine", sp.engine))
-            if sp.tier:
-                attrs.append(attr("tier", sp.tier))
-            attrs += [attr(k, v) for k, v in sp.attrs.items()]
-            one = {
-                "traceId": trace_hex(sp.trace_id),
-                "spanId": span_hex(sp.span_id),
-                "name": sp.name,
-                "kind": 1,           # SPAN_KIND_INTERNAL
-                "startTimeUnixNano": nanos(sp.t_start),
-                "endTimeUnixNano": nanos(t_end),
-                "attributes": attrs,
-            }
-            if sp.parent_id is not None:
-                one["parentSpanId"] = span_hex(sp.parent_id)
-            otlp_spans.append(one)
-        return {"resourceSpans": [{
-            "resource": {"attributes": [
-                attr("service.name", "repro-fleet"),
-                attr("repro.dropped_spans", self.dropped),
-            ]},
-            "scopeSpans": [{
-                "scope": {"name": "repro.fleet.tracing"},
-                "spans": otlp_spans,
-            }],
-        }]}
-
-    def export_otlp(self, path: str):
-        with open(path, "w") as f:
-            json.dump(self.otlp_trace(), f)

@@ -116,6 +116,7 @@ def decode_attention(q, k_cache, v_cache, abs_pos, positions, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(pos2, qt, kt, vt, abs_pos)
     return out.reshape(B, 1, H, D)
 
@@ -230,5 +231,6 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, positions, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(pt_flat, pos, qt, kt, vt)
     return out.reshape(B, 1, H, D)

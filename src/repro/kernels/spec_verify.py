@@ -82,6 +82,7 @@ def spec_accept(draft_tokens, draft_probs, target_probs, u, *,
             jax.ShapeDtypeStruct((1, V), jnp.float32),
         ],
         interpret=interpret,
+        name="spec_verify",
     )(draft_tokens.reshape(1, g).astype(jnp.int32),
       u.reshape(1, g).astype(jnp.float32),
       draft_probs.astype(jnp.float32),

@@ -269,10 +269,6 @@ def main(argv: list[str] | None = None):
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write the run's Chrome trace-event JSON here "
                          "(open in Perfetto / chrome://tracing)")
-    ap.add_argument("--otlp-out", default=None, metavar="PATH",
-                    help="write the run's spans as an OTLP-JSON "
-                         "ExportTraceServiceRequest here (feed to any "
-                         "OpenTelemetry collector/backend)")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write a Prometheus text exposition of the "
                          "fleet metrics registry here")
@@ -541,11 +537,6 @@ def main(argv: list[str] | None = None):
         fleet.tracer.export_chrome(args.trace_out)
         print(f"trace: {args.trace_out} ({len(fleet.tracer.spans)} spans"
               f" -- open in Perfetto / chrome://tracing)")
-    if args.otlp_out and fleet.tracer is not None:
-        fleet.tracer.close_open(reason="run complete")
-        fleet.tracer.export_otlp(args.otlp_out)
-        print(f"otlp: {args.otlp_out} ({len(fleet.tracer.spans)} spans"
-              f" -- OTLP-JSON, collector-ready)")
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:
             f.write(fleet.telemetry.prometheus_text())

@@ -21,7 +21,6 @@ import inspect
 import time
 import warnings
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Optional
 
 import jax
@@ -31,7 +30,7 @@ import numpy as np
 from repro import sharding as shd
 from repro.configs.base import ModelConfig
 from repro.models.model import forward, make_cache, vocab_mask_logits
-from repro.serving.program_cache import get_programs
+from repro.serving.program_cache import bind, get_programs
 from repro.serving.sampling import policy_probs, sample
 
 
@@ -51,6 +50,21 @@ def _call_profile_hook(hook, key: str, wall_s: float, *,
         hook(key, wall_s, cache_hit=cache_hit)
     else:
         hook(key, wall_s)
+
+
+def span(name: str, **counts):
+    """A host span called ``name`` on the profiler's own timeline, the
+    one the device ops are on, with ``counts`` as its stats (more can be
+    added before it closes with ``.set_metadata``).  Counts are host
+    values the caller already holds, or functions of none that work
+    them out: one read from a device array would add the very wait these
+    spans are there to show.  Counts are attached, and those functions
+    called, only while a trace is being recorded; with none running a
+    span costs about a microsecond and computes nothing."""
+    if not counts or not jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(
+        name, **{k: v() if callable(v) else v for k, v in counts.items()})
 
 
 @jax.tree_util.register_dataclass
@@ -194,15 +208,15 @@ class Engine:
         self._programs, self.program_cache_hit = get_programs(
             "dense", cfg, mesh, rules, slots=slots, max_len=max_len,
             build=lambda: {
-                "decode": jax.jit(partial(_decode_step, cfg=cfg,
-                                          mesh=mesh, rules=rules)),
-                "prefill": jax.jit(partial(_prefill, cfg=cfg, mesh=mesh,
-                                           rules=rules),
+                "decode": jax.jit(bind(_decode_step, cfg=cfg, mesh=mesh,
+                                       rules=rules)),
+                "prefill": jax.jit(bind(_prefill, cfg=cfg, mesh=mesh,
+                                        rules=rules),
                                    static_argnames=("slot", "plen")),
-                "verify": jax.jit(partial(_verify_window, cfg=cfg,
-                                          mesh=mesh, rules=rules)),
-                "probs": jax.jit(partial(_decode_step_probs, cfg=cfg,
-                                         mesh=mesh, rules=rules)),
+                "verify": jax.jit(bind(_verify_window, cfg=cfg,
+                                       mesh=mesh, rules=rules)),
+                "probs": jax.jit(bind(_decode_step_probs, cfg=cfg,
+                                      mesh=mesh, rules=rules)),
             })
         self._decode_fn = self._programs.fns["decode"]
         self._prefill_fn = self._programs.fns["prefill"]
@@ -220,25 +234,30 @@ class Engine:
     def _profiled(self, key: str, fn):
         """Run ``fn``; if this is the first invocation of program ``key``
         on this engine, time it to completion (``block_until_ready``)
-        and report to ``profile_hook``.  Warm keys run untouched, and a
-        key is marked warm even with no hook attached so a hook wired in
-        later never reports an already-compiled program as a build.  A
-        key another engine already ran through the shared program set is
-        reported as a cache hit: the wall time is the (tiny) first
-        dispatch, not a compile."""
+        and report to ``profile_hook``.  ``key`` names the program and
+        every static argument it is compiled for
+        (``prefill[slot=0,plen=6]``), so each key is one executable.
+        Warm keys run untouched, and a key is marked warm even with no
+        hook attached so a hook wired in later never reports an
+        already-compiled program as a build.  A key another engine
+        already ran through the shared program set is reported as a
+        cache hit: the wall time is the (tiny) first dispatch, not a
+        compile.  The first run is an ``engine.first_run`` span."""
         if key in self._compiled:
             return fn()
         self._compiled.add(key)
         shared = self._programs.compiled
         warm = key in shared
         shared.add(key)
-        if self.profile_hook is None:
-            return fn()
-        t0 = time.perf_counter()
-        out = fn()
-        jax.block_until_ready(out)
-        _call_profile_hook(self.profile_hook, key,
-                           time.perf_counter() - t0, cache_hit=warm)
+        with span("engine.first_run", program=key.split("[")[0], key=key):
+            if self.profile_hook is None:
+                return fn()
+            t0 = time.perf_counter()
+            out = fn()
+            with span("engine.sync", site="first_run"):
+                jax.block_until_ready(out)
+            _call_profile_hook(self.profile_hook, key,
+                               time.perf_counter() - t0, cache_hit=warm)
         return out
 
     # -- state ------------------------------------------------------------
@@ -313,7 +332,7 @@ class Engine:
             top_k=self.state.top_k.at[slot].set(req.top_k))
         prompt = jnp.asarray(prefix, jnp.int32)[None]
         self.state = self._profiled(
-            f"prefill[plen={plen}]",
+            f"prefill[slot={slot},plen={plen}]",
             lambda: self._prefill_fn(self.params, self.state, prompt,
                                      slot=slot, plen=plen))
         return True

@@ -29,9 +29,7 @@ sized for memory.
 from __future__ import annotations
 
 import dataclasses
-import time
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -40,11 +38,10 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models.layers import make_paged_attn_cache
 from repro.models.model import forward
-from repro.serving.engine import (Request, SlotArrays, SlotSnapshot,
-                                  _call_profile_hook, request_from_dict,
-                                  request_to_dict)
+from repro.serving.engine import (Engine, Request, SlotArrays, SlotSnapshot,
+                                  request_from_dict, request_to_dict, span)
 from repro.serving.prefix_cache import PrefixCache
-from repro.serving.program_cache import get_programs
+from repro.serving.program_cache import bind, get_programs
 from repro.serving.sampling import sample
 
 
@@ -175,13 +172,13 @@ class PagedEngine:
             "paged", cfg, mesh, rules, slots=rows, max_len=max_len,
             page_size=page_size, pages=self.pages,
             build=lambda: {
-                "decode": jax.jit(partial(_paged_decode_step, cfg=cfg,
-                                          mesh=mesh, rules=rules)),
-                "prefill": jax.jit(partial(_paged_prefill, cfg=cfg,
-                                           mesh=mesh, rules=rules),
+                "decode": jax.jit(bind(_paged_decode_step, cfg=cfg,
+                                       mesh=mesh, rules=rules)),
+                "prefill": jax.jit(bind(_paged_prefill, cfg=cfg,
+                                        mesh=mesh, rules=rules),
                                    static_argnames=("slot", "plen")),
-                "suffix": jax.jit(partial(_paged_suffix_prefill, cfg=cfg,
-                                          mesh=mesh, rules=rules),
+                "suffix": jax.jit(bind(_paged_suffix_prefill, cfg=cfg,
+                                       mesh=mesh, rules=rules),
                                   static_argnames=("slot", "slen")),
             })
         self._decode_fn = self._programs.fns["decode"]
@@ -210,21 +207,7 @@ class PagedEngine:
     def page_bytes(self) -> int:
         return self.kv_token_bytes * self.page_size
 
-    def _profiled(self, key: str, fn):
-        if key in self._compiled:
-            return fn()
-        self._compiled.add(key)
-        shared = self._programs.compiled
-        warm = key in shared        # another engine already compiled this
-        shared.add(key)
-        if self.profile_hook is None:
-            return fn()
-        t0 = time.perf_counter()
-        out = fn()
-        jax.block_until_ready(out)
-        _call_profile_hook(self.profile_hook, key,
-                           time.perf_counter() - t0, cache_hit=warm)
-        return out
+    _profiled = Engine._profiled
 
     # -- state ------------------------------------------------------------
     def _fresh_state(self, seed: int) -> PagedEngineState:
@@ -308,7 +291,10 @@ class PagedEngine:
 
     # -- request lifecycle --------------------------------------------------
     def _row_pages(self, row: int) -> list[int]:
-        pt = np.asarray(self.state.page_table[row])
+        # waits for every program already dispatched that writes the
+        # page table (a suffix scan, a decode step)
+        with span("engine.sync", site="page_table"):
+            pt = np.asarray(self.state.page_table[row])
         return [int(p) for p in pt if p >= 0]
 
     def add_request(self, req: Request, *,
@@ -327,6 +313,10 @@ class PagedEngine:
         free = self.free_slots
         if not free:
             return False
+        with span("engine.admit") as sp:
+            return self._admit(req, free[0], committed, sp)
+
+    def _admit(self, req: Request, row: int, committed, sp) -> bool:
         need = len(req.prompt) + req.max_new_tokens
         assert need <= self.max_len
         prefix = np.asarray(req.prompt, np.int32)
@@ -337,8 +327,10 @@ class PagedEngine:
         plen = len(prefix)
         cache = self.prefix_cache
         tenant = getattr(req, "tenant", "")
-        full_nodes, tail, hit = (cache.match(tenant, prefix)
-                                 if cache is not None else ([], None, 0))
+        with span("prefix.match"):
+            full_nodes, tail, hit = (cache.match(tenant, prefix)
+                                     if cache is not None
+                                     else ([], None, 0))
         n_ref = len(full_nodes)
         need_priv = self._pages_for(need) - n_ref
         pages = self.allocator.alloc(need_priv, req.rid)
@@ -349,7 +341,8 @@ class PagedEngine:
             pages = self.allocator.alloc(need_priv, req.rid)
         if pages is None:
             return False
-        row = free[0]
+        # counted once admitted: a refused admission's span has none
+        sp.set_metadata(prompt=plen, cached=hit)
         req.slot = row
         self.requests[row] = req
         if extra:
@@ -378,10 +371,11 @@ class PagedEngine:
             self._warm_start(row, prefix)
         elif hit == 0:
             prompt = jnp.asarray(prefix, jnp.int32)[None]
-            self.state = self._profiled(
-                f"prefill[plen={plen}]",
-                lambda: self._prefill_fn(self.params, self.state, prompt,
-                                         slot=row, plen=plen))
+            with span("engine.prefill"):
+                self.state = self._profiled(
+                    f"prefill[slot={row},plen={plen}]",
+                    lambda: self._prefill_fn(self.params, self.state,
+                                             prompt, slot=row, plen=plen))
         else:
             # suffix-only prefill: seed the covered region, then forward
             # just the uncovered tokens through the decode-mode program
@@ -390,10 +384,11 @@ class PagedEngine:
             self._warm_start(row, prefix[:hit])
             suffix = jnp.asarray(prefix[hit:], jnp.int32)[None]
             slen = plen - hit
-            self.state = self._profiled(
-                f"suffix[slen={slen}]",
-                lambda: self._suffix_fn(self.params, self.state, suffix,
-                                        slot=row, slen=slen))
+            with span("engine.suffix_prefill", tokens=slen):
+                self.state = self._profiled(
+                    f"suffix[slot={row},slen={slen}]",
+                    lambda: self._suffix_fn(self.params, self.state,
+                                            suffix, slot=row, slen=slen))
         if cache is not None:
             self._donate(row, tenant, prefix, hit)
             cache.account(hit)
@@ -514,37 +509,48 @@ class PagedEngine:
         row's own tail page is about to be written by decode)."""
         cache, ps = self.prefix_cache, self.page_size
         nodes = self._shared[row]
-        row_pages = self._row_pages(row)
-        for d in range(len(nodes), len(prefix) // ps):
-            node = cache.adopt(tenant, prefix, d, row_pages[d])
-            if node is None:
-                # a peer cached this block since we matched; keep our
-                # private page (swapping pages mid-request would break
-                # the row's bit-exactness) and stop extending the chain
-                return
-            cache.acquire([node])
-            nodes.append(node)
-        if len(prefix) % ps and hit < len(prefix):
-            d = len(prefix) // ps
-            cache.adopt_tail(tenant, prefix,
-                             lambda dst: self._copy_page(row_pages[d], dst))
+        with span("prefix.donate"):
+            row_pages = self._row_pages(row)
+            for d in range(len(nodes), len(prefix) // ps):
+                node = cache.adopt(tenant, prefix, d, row_pages[d])
+                if node is None:
+                    # a peer cached this block since we matched; keep
+                    # our private page (swapping pages mid-request would
+                    # break the row's bit-exactness) and stop extending
+                    # the chain
+                    return
+                cache.acquire([node])
+                nodes.append(node)
+            if len(prefix) % ps and hit < len(prefix):
+                d = len(prefix) // ps
+                cache.adopt_tail(
+                    tenant, prefix,
+                    lambda dst: self._copy_page(row_pages[d], dst))
 
     def step(self, *, auto_retire: bool = True) -> dict[str, int]:
         if not self.requests:
             return {}
-        self.state, toks = self._profiled(
-            "decode", lambda: self._decode_fn(self.params, self.state))
-        toks = np.asarray(toks)
-        emitted = {}
-        for row, req in list(self.requests.items()):
-            if req.done:
-                continue
-            t = int(toks[row])
-            req.output.append(t)
-            emitted[req.rid] = t
-            if auto_retire and len(req.output) >= req.max_new_tokens:
-                req.done = True
-                self.retire(row)
+        # keys: the positions this step's attention reads, summed over
+        # the live rows: each row's prompt and output, and the token fed
+        live = lambda: [r for r in self.requests.values()  # noqa: E731
+                        if not r.done]
+        with span("engine.decode", rows=lambda: len(live()),
+                  keys=lambda: sum(len(r.prompt) + len(r.output) + 1
+                                   for r in live())):
+            self.state, toks = self._profiled(
+                "decode", lambda: self._decode_fn(self.params, self.state))
+            with span("engine.sync", site="tokens"):
+                toks = np.asarray(toks)
+            emitted = {}
+            for row, req in list(self.requests.items()):
+                if req.done:
+                    continue
+                t = int(toks[row])
+                req.output.append(t)
+                emitted[req.rid] = t
+                if auto_retire and len(req.output) >= req.max_new_tokens:
+                    req.done = True
+                    self.retire(row)
         return emitted
 
     def retire(self, row: int):

@@ -26,15 +26,16 @@ pinned (the entry holds strong references), so a recycled ``id()`` can
 never alias two configs.
 
 Each entry also tracks which program keys (``"decode"``,
-``"prefill[plen=N]"``, ...) have already executed once through it --
-i.e. are actually compiled -- so an engine's profile hook can report a
-cache-served program as ``build_s ~ 0`` with a ``cache_hit`` annotation
-instead of claiming a fresh multi-second build (time-to-useful spans
-stay honest).
+``"prefill[slot=R,plen=N]"``, ...) have already executed once through
+it -- i.e. are actually compiled -- so an engine's profile hook can
+report a cache-served program as ``build_s ~ 0`` with a ``cache_hit``
+annotation instead of claiming a fresh multi-second build
+(time-to-useful spans stay honest).
 """
 
 from __future__ import annotations
 
+import functools
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -49,6 +50,14 @@ class ProgramSet:
     compiled: set[str] = field(default_factory=set)
     served: int = 0                  # engines constructed from this entry
     pins: tuple = ()                 # strong refs: id()-keyed parts stay alive
+
+
+def bind(fn: Callable, **bound) -> Callable:
+    """``fn`` with ``bound`` keyword arguments fixed, under ``fn``'s own
+    name.  XLA names a jitted program's module after the callable
+    (``jit__paged_decode_step``); a bare ``partial`` has no name, and
+    every program it makes would be ``jit__unknown``."""
+    return functools.update_wrapper(functools.partial(fn, **bound), fn)
 
 
 _lock = threading.Lock()

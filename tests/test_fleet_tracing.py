@@ -426,7 +426,7 @@ def test_engine_profile_hook_fires_once_per_program_key():
     while not req.done:
         eng.step()
     keys = [k for k, _ in calls]
-    assert keys == ["prefill[plen=4]", "decode"]
+    assert keys == ["prefill[slot=0,plen=4]", "decode"]
     assert all(dt > 0 for _, dt in calls)
     # same geometry again: both programs are warm, nothing re-reports
     req2 = Request("q", np.arange(4), max_new_tokens=2)
@@ -434,44 +434,3 @@ def test_engine_profile_hook_fires_once_per_program_key():
     while not req2.done:
         eng.step()
     assert len(calls) == 2
-
-
-def test_otlp_export_structure(tmp_path):
-    """OTLP-JSON export: one ExportTraceServiceRequest whose spans
-    mirror the tracer's store -- resource/scope framing, 32/16-char hex
-    ids, parent links resolving within the same trace, nanosecond
-    timestamps ordered, and ints carried as strings per the OTLP JSON
-    mapping."""
-    fleet = FleetController(
-        [EngineHandle("edge", mk_engine(seed=0, slots=2), EDGE)],
-        authority=TrustAuthority())
-    for i in range(2):
-        t = fleet.submit(RequestSpec(prompt=np.arange(5), rid=f"r{i}",
-                                     max_new_tokens=4))
-        while not t.done:
-            fleet.step()
-    fleet.tracer.close_open(reason="test done")
-    out = tmp_path / "otlp.json"
-    fleet.tracer.export_otlp(str(out))
-    doc = json.loads(out.read_text())
-    (rs,) = doc["resourceSpans"]
-    res_attrs = {a["key"]: a["value"] for a in rs["resource"]["attributes"]}
-    assert res_attrs["service.name"]["stringValue"] == "repro-fleet"
-    (ss,) = rs["scopeSpans"]
-    assert ss["scope"]["name"] == "repro.fleet.tracing"
-    spans = ss["spans"]
-    assert len(spans) == len(fleet.tracer.spans)
-    by_trace: dict[str, set] = {}
-    for sp in spans:
-        assert len(sp["traceId"]) == 32 and len(sp["spanId"]) == 16
-        assert int(sp["endTimeUnixNano"]) >= int(sp["startTimeUnixNano"])
-        by_trace.setdefault(sp["traceId"], set()).add(sp["spanId"])
-        for attr in sp["attributes"]:
-            v = attr["value"]
-            if "intValue" in v:       # OTLP JSON: 64-bit ints as strings
-                assert isinstance(v["intValue"], str)
-    for sp in spans:                  # parents resolve within the trace
-        if "parentSpanId" in sp:
-            assert sp["parentSpanId"] in by_trace[sp["traceId"]]
-    # both requests produced distinct traces
-    assert len(by_trace) >= 2

@@ -116,6 +116,6 @@ def test_profile_hook_reports_cache_hits_honestly():
     first = {}                                 # e1 pays each compile once
     for k, hit in seen1:
         first.setdefault(k, hit)
-    assert first == {"prefill[plen=6]": False, "decode": False}
+    assert first == {"prefill[slot=0,plen=6]": False, "decode": False}
     assert seen2 and all(hit for _, hit in seen2)   # e2 rides e1's programs
-    assert set(legacy) == {"prefill[plen=6]", "decode"}  # no crash
+    assert set(legacy) == {"prefill[slot=0,plen=6]", "decode"}  # no crash
