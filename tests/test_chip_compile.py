@@ -1,5 +1,7 @@
 """Compile rehearsals: the main-path Pallas kernels at llama-1.5b widths
-(H=16, KV=8, D=128, V=32000) compiled for a described TPU v5e chip.
+(H=16, KV=8, D=128, V=32000), and the paged decode kernel at the
+benchmark cell's DeepSeek-7B shapes, compiled for a described TPU v5e
+chip.
 
 Nothing runs: the TPU compiler installed next to JAX compiles for a
 chip that is described, not attached, and refuses what the chip would
@@ -55,6 +57,20 @@ def test_paged_decode_attention_compiles(one_chip):
                  q, k, v, pt, pos),
              ((B, 1, H, D), BF16), ((pages, ps, KV, D), BF16),
              ((pages, ps, KV, D), BF16), ((B, NP), jnp.int32),
+             ((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("B", [16, 1], ids=["decode", "suffix"])
+def test_paged_decode_attention_compiles_at_ds7b_cell(one_chip, B):
+    """DeepSeek-7B's cell: 600 pages of 16 tokens, 144-page tables, 32
+    query and 32 KV heads of 128 (MHA), bf16; 16 rows in the decode
+    step, one in each suffix token."""
+    pages, ps, NP, heads = 600, 16, 144, 32
+    _compile(one_chip,
+             lambda q, k, v, pt, pos: paged_decode_attention(
+                 q, k, v, pt, pos),
+             ((B, 1, heads, D), BF16), ((pages, ps, heads, D), BF16),
+             ((pages, ps, heads, D), BF16), ((B, NP), jnp.int32),
              ((B,), jnp.int32))
 
 

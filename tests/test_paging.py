@@ -15,7 +15,8 @@ import pytest
 from repro.configs import get
 from repro.configs.tiny import make_tiny
 from repro.core.migration import pack_slot, repack_slot, unpack_slot
-from repro.kernels.decode_attention import paged_decode_attention
+from repro.kernels.decode_attention import (_paged_blocking,
+                                           paged_decode_attention)
 from repro.models.attention import paged_decode_attend
 from repro.serving.engine import Engine, Request
 from repro.serving.paged import PageAllocator, PagedEngine
@@ -377,6 +378,69 @@ def test_paged_decode_attention_randomized_tables():
         oref = paged_decode_attend(q, k_pool, v_pool, jnp.asarray(pt),
                                    jnp.asarray(pos), page_size=ps)
         assert float(jnp.abs(o - oref).max()) < 2e-5, seed
+
+
+@pytest.mark.parametrize("H", [8, 16], ids=["mha", "gqa"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("mode", ["plain", "window", "softcap"])
+def test_paged_decode_attention_page_blocks(H, dtype, mode):
+    """The kernel walks each row's live pages a block at a time: rows
+    whose position falls mid-block, on the last key of a block and on
+    the first of the next, mapped pages past the position (which must
+    not count), an unmapped hole, an all -1 row (zeros), and a window
+    that spans two blocks, over NP pages that are no multiple of the
+    pages per block."""
+    KV, D, ps, NP, P = 8, 128, 16, 40, 64
+    ppb, kvb = _paged_blocking(ps, KV, D, jnp.dtype(dtype).itemsize, NP)
+    assert kvb == KV and 1 < ppb < NP and NP % ppb, (ppb, NP)
+    rng = np.random.default_rng(5)
+    B = 6
+    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), dtype)
+    k_pool = jnp.asarray(rng.standard_normal((P, ps, KV, D)), dtype)
+    v_pool = jnp.asarray(rng.standard_normal((P, ps, KV, D)), dtype)
+    pt = rng.integers(0, P, (B, NP)).astype(np.int32)   # pages may repeat
+    pos = np.asarray([
+        (ppb + (NP - ppb) // 2) * ps + 7,  # mid-block, mapped pages after
+        ppb * ps - 1,                      # last key of block 0
+        ppb * ps + 2,                      # just into block 1
+        NP * ps - 1,                       # whole table, last block short
+        3 * ps + 5,                        # early, 36 mapped pages past it
+        ppb * ps + 9,                      # all -1: dead row
+    ], np.int32)
+    pt[3, ppb - 2] = -1                                 # a hole
+    pt[5] = -1
+    kw = {}
+    if mode == "window":
+        kw["window"] = 3 * ps + 5    # row 2's window spans blocks 0, 1
+    elif mode == "softcap":
+        kw["softcap"] = 20.0
+    o = paged_decode_attention(q, k_pool, v_pool, jnp.asarray(pt),
+                               jnp.asarray(pos), interpret=True, **kw)
+    oref = paged_decode_attend(q, k_pool, v_pool, jnp.asarray(pt),
+                               jnp.asarray(pos), page_size=ps, **kw)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    err = float(jnp.abs(o.astype(jnp.float32)
+                        - oref.astype(jnp.float32)).max())
+    assert err < _tol(dtype), (mode, err)
+    assert float(jnp.abs(o[5]).max()) == 0.0
+
+
+def test_paged_decode_attention_head_groups():
+    """Where one page of every head outgrows a block, the heads split
+    into groups along a second grid axis, each copying its own slice of
+    every page."""
+    KV, D, ps, NP, P, B = 32, 128, 128, 3, 5, 2
+    ppb, kvb = _paged_blocking(ps, KV, D, 4, NP)
+    assert kvb < KV, kvb
+    rng = np.random.default_rng(9)
+    q = jnp.asarray(rng.standard_normal((B, 1, KV, D)), jnp.float32)
+    k_pool = jnp.asarray(rng.standard_normal((P, ps, KV, D)), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal((P, ps, KV, D)), jnp.float32)
+    pt = jnp.asarray([[4, 0, 2], [1, 3, -1]], jnp.int32)
+    pos = jnp.asarray([NP * ps - 1, ps + 40], jnp.int32)
+    o = paged_decode_attention(q, k_pool, v_pool, pt, pos, interpret=True)
+    oref = paged_decode_attend(q, k_pool, v_pool, pt, pos, page_size=ps)
+    assert float(jnp.abs(o - oref).max()) < 2e-5
 
 
 # -- the retired entry points warn and delegate ------------------------------
