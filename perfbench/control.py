@@ -44,11 +44,11 @@ def main(argv=None):
     import harness
     import sut
     from reference import check
-    from reference import model as ref
 
     bench = cells.load_benchmark()
     cell = cells.workload(bench, args.workload)
     conf = cells.config(bench, cell["config"])
+    ref = cells.reference(conf)
     devices = entry.require_chip(jax, cell["chips"])
     for seed in [int(s) for s in args.seeds.split(",")]:
         t0 = time.perf_counter()

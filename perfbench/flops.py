@@ -8,7 +8,9 @@ bytes.  A multiply-add is two operations.
 Model counts are the operations a token requires: its matrix products
 (for an MoE layer the routed top-k experts, the shared experts and the
 router only, whatever the program runs), attention over the keys it
-attends to, and the output head where its logits are needed.
+attends to, and the output head where its logits are needed.  Each
+architecture counts its own (``token_ops`` of its reference module,
+found by the configuration's ``model_type``).
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-import weights as W
+import cells
 
 BF16 = 2
 
@@ -46,24 +48,13 @@ def paged_decode_attention(ctx_lens, H: int, KV: int,
 
 def token_ops(conf: dict, ctx: int, head: bool) -> float:
     """Operations one token needs at a position attending ``ctx`` keys."""
-    m = W.dims(conf)
-    d, H, KV, D = m["d"], m["H"], m["KV"], m["Dh"]
-    total = 0.0
-    for kind in W.layer_kinds(conf):
-        total += 2.0 * d * (H + 2 * KV) * D + 2.0 * H * D * d
-        total += 4.0 * H * D * ctx
-        if kind == "dense":
-            total += 6.0 * d * m["ff"]
-        else:
-            total += 6.0 * d * m["dx"] * (m["k"] + m["S"]) + 2.0 * d * m["E"]
-    if head:
-        total += 2.0 * d * m["V"]
-    return total
+    return cells.reference(conf).token_ops(conf, ctx, head)
 
 
 def prompt_ops(conf: dict, start: int, n: int) -> float:
     """Tokens start..start+n-1 of a prompt, no logits needed."""
-    return sum(token_ops(conf, p + 1, False) for p in range(start, start + n))
+    count = cells.reference(conf).token_ops
+    return sum(count(conf, p + 1, False) for p in range(start, start + n))
 
 
 def peak(device_kind: str) -> dict:

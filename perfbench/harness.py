@@ -32,7 +32,6 @@ import loadgen
 import sut
 import trace_reduce
 from reference import check
-from reference import model as ref
 
 HERE = Path(__file__).resolve().parent
 TRACE_DIR = HERE / ".trace"
@@ -298,6 +297,7 @@ def run(*, config_name: str, conf: dict, traffic_spec: dict,
     sample = check.sample(done, seed)
     exact, chosen = [], []
     if sample:
+        ref = cells.reference(conf)
         seqs, rows = map(list, zip(*(check.rows_of(r) for r in sample)))
         exact = ref.logits_at(conf, seed, seqs, rows)
         chosen = ([lg.argmax(-1) for lg in

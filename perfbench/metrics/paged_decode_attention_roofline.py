@@ -4,11 +4,11 @@ and the live KV bytes each row must read, from the calls' shapes) over
 the kernel's device time.  One call per layer for every decode step and
 for every token of a suffix prefill admitted in the window."""
 
+import cells
 import flops
 import trace_reduce
-import weights as W
 
-KERNEL = trace_reduce.pallas_call("s32[")    # fed the page table first
+KERNEL = trace_reduce.kernel("paged_decode_attention")
 
 
 def read(ctx):
@@ -17,8 +17,8 @@ def read(ctx):
     ns = trace_reduce.time_where(ctx.trace, KERNEL, *ctx.trace_span)
     if not ns:
         return None
-    m = W.dims(ctx.conf)
-    layers = ctx.conf["num_hidden_layers"]
+    m = cells.reference(ctx.conf).paged_attention(ctx.conf)
+    layers = m["layers"]
     peak = ctx.peak()
 
     def t(lens):

@@ -1,11 +1,13 @@
 """The system under test: the program's config, weights, engine and fleet.
 
-This is the only module of the benchmark that imports the program
-(``repro``).  It builds the program's ``ModelConfig`` from a
-configuration file, hands the program weights drawn by ``weights.py``
-(one jitted call, on the device, in the served types), and stands up the
-normal serving path: one ``PagedEngine`` with the prefix cache armed
-behind a ``FleetController``.
+This module and the architectures' program adapters
+(``programs/<model_type>.py``, found by ``cells.program``) are the only
+parts of the benchmark that import the program (``repro``).  It builds
+the program's ``ModelConfig`` from a configuration file through the
+adapter, hands the program weights drawn by ``weights.py`` from the
+reference's leaf table (one jitted call, on the device, in the served
+types), and stands up the normal serving path: one ``PagedEngine`` with
+the prefix cache armed behind a ``FleetController``.
 """
 
 from __future__ import annotations
@@ -15,11 +17,12 @@ from pathlib import Path
 
 import jax
 
+import cells
 import weights as W
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from repro.configs.base import BlockDef, LayerSpec, ModelConfig, MoESpec  # noqa: E402
+from repro.configs.base import ModelConfig  # noqa: E402
 from repro.core import daemon  # noqa: E402
 from repro.fleet import (EngineHandle, FleetController, Rebalancer,  # noqa: E402
                          RequestSpec)
@@ -33,67 +36,39 @@ __all__ = ["RequestSpec", "ops", "model_config", "program_params", "serve",
 
 
 def model_config(name: str, conf: dict) -> ModelConfig:
-    m = W.dims(conf)
-    kinds = W.layer_kinds(conf)
-    blocks, i = [], 0
-    while i < len(kinds):
-        j = i
-        while j < len(kinds) and kinds[j] == kinds[i]:
-            j += 1
-        blocks.append(BlockDef((LayerSpec("attn", kinds[i]),), repeats=j - i))
-        i = j
-    moe = None
-    if m["E"]:
-        moe = MoESpec(num_experts=m["E"], top_k=m["k"], d_expert=m["dx"],
-                      num_shared=m["S"])
-    return ModelConfig(
-        name=name, family="moe" if moe else "dense", d_model=m["d"],
-        num_heads=m["H"], num_kv_heads=m["KV"], head_dim=m["Dh"],
-        d_ff=m["ff"], vocab_size=m["V"], blocks=tuple(blocks), moe=moe,
-        rope_theta=float(conf.get("rope_theta", 10000.0)),
-        norm_eps=float(conf.get("rms_norm_eps", 1e-6)),
-        act=conf.get("hidden_act", "silu"),
-        max_position=conf.get("max_position_embeddings", 1 << 20),
-        tie_embeddings=bool(conf.get("tie_word_embeddings", False)))
+    return cells.program(conf).model_config(name, conf)
 
 
-def _layer_tree(leaves: dict, kind: str) -> dict:
-    """Canonical leaf names -> the program's per-layer parameter tree."""
-    t = {"attn": {"ln": {"scale": leaves["attn_norm"]},
-                  **{n: leaves[n] for n in ("wq", "wk", "wv", "wo")}}}
-    if kind == "dense":
-        t["mlp"] = {"ln": {"scale": leaves["mlp_norm"]},
-                    **{n: leaves[n] for n in ("w_gate", "w_up", "w_down")}}
-    else:
-        t["moe"] = {"ln": {"scale": leaves["mlp_norm"]},
-                    "router": leaves["router"], "w_gate": leaves["e_gate"],
-                    "w_up": leaves["e_up"], "w_down": leaves["e_down"]}
-        if "s_gate" in leaves:
-            t["moe"]["shared"] = {"w_gate": leaves["s_gate"],
-                                  "w_up": leaves["s_up"],
-                                  "w_down": leaves["s_down"]}
-    return t
+def _stacks(cfg: ModelConfig) -> list[list[list[int]]]:
+    """For each block of the program's tree, the layers behind each of
+    its positions: a block repeats its period of layers, and each
+    position's leaves are stacked over the repeats."""
+    out, i = [], 0
+    for b in cfg.blocks:
+        p = len(b.layers)
+        out.append([[i + r * p + j for r in range(b.repeats)]
+                    for j in range(p)])
+        i += p * b.repeats
+    return out
 
 
 def program_params(conf: dict, cfg: ModelConfig, seed: int):
     """The program's parameter tree, drawn on the device in one call."""
-    kinds = W.layer_kinds(conf)
-
-    def build(key):
-        g = W.global_weights(key, conf)
-        blocks, i = [], 0
-        for b in cfg.blocks:
-            layers = list(range(i, i + b.repeats))
-            i += b.repeats
-            blocks.append([_layer_tree(W.stacked_layers(key, conf, layers),
-                                       kinds[layers[0]])])
-        tree = {"embed": g["embed"], "blocks": blocks,
-                "final_norm": {"scale": g["final_norm"]}}
-        if not cfg.tie_embeddings:
-            tree["lm_head"] = g["lm_head"]
-        return tree
-
-    params = jax.jit(build)(W.seed_key(seed))
+    ref, prog = cells.reference(conf), cells.program(conf)
+    kinds = ref.layer_kinds(conf)
+    stacks = _stacks(cfg)
+    flat = [s for block in stacks for s in block]
+    if sorted(i for s in flat for i in s) != list(range(len(kinds))) \
+            or any(len({kinds[i] for i in s}) != 1 for s in flat):
+        raise RuntimeError("the program's blocks do not follow the "
+                           "reference's layers")
+    g, drawn = W.model(seed, ref.global_leaves(conf),
+                       [(ref.layer_leaves(conf, kinds[s[0]]), s)
+                        for s in flat])
+    drawn = iter(drawn)
+    params = {**prog.global_tree(g, cfg),
+              "blocks": [[prog.layer_tree(next(drawn), kinds[s[0]])
+                          for s in block] for block in stacks]}
     want = abstract_params(cfg)
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                        params)

@@ -59,7 +59,7 @@ def main(argv=None):
                         seed=seeds[0], clock=clock, annotate=False)
     print(json.dumps({"setup_s": clock() - t}), flush=True)
     readers = {n: cells.metric_reader(n)
-               for n in ("output_tok_s", "ttft_p95_ms", "itl_p99_ms")}
+               for n in ("output_tok_s", "ttft_p95_ms", "fleet.itl_p99_ms")}
     for seed in seeds:
         for value in values:
             spec = dict(base, **{args.key: value})

@@ -35,21 +35,29 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+import cells  # noqa: E402
 import harness  # noqa: E402
 import sut  # noqa: E402
 from reference import check  # noqa: E402
-from reference import model as ref  # noqa: E402
 
+# norm_topk_prob true: the only routing the program implements today
 TINY = {
     "model_type": "deepseek", "hidden_size": 128, "intermediate_size": 256,
     "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 32,
     "num_hidden_layers": 3, "vocab_size": 1024, "rope_theta": 10000.0,
     "rms_norm_eps": 1e-6, "n_routed_experts": 8, "num_experts_per_tok": 2,
     "moe_intermediate_size": 64, "n_shared_experts": 2,
-    "first_k_dense_replace": 1,
+    "first_k_dense_replace": 1, "norm_topk_prob": True,
     "serving": {"page_size": 16, "rows": 4, "kv_pages": 64},
     "check": {"served_logit_gap": 0.15},   # sound <= 0.036, fp8 >= 0.53
 }
+# the same limit holds the Llama decoder: sound 0.012, fp8 0.33 (seed 7)
+TINY_DENSE = {k: v for k, v in TINY.items() if k not in (
+    "n_routed_experts", "num_experts_per_tok", "moe_intermediate_size",
+    "n_shared_experts", "first_k_dense_replace", "norm_topk_prob")}
+TINY_DENSE["model_type"] = "llama"
+ARCHS = [pytest.param(TINY_DENSE, id="llama"),
+         pytest.param(TINY, id="deepseek")]
 TRAFFIC = {
     "loop": "closed", "sessions": 4, "think_s": 0.05, "ramp_s": 0.5,
     "tenants": 2,
@@ -74,8 +82,8 @@ def first_decode_refeeds() -> bool:
     eng.add_request(Request("p", prompt, max_new_tokens=1))
     tok = eng.step()["p"]
     twice = np.concatenate([prompt, prompt[-1:]])
-    plain, dup = ref.logits_at(TINY, 1, [prompt, twice],
-                               [[len(prompt) - 1], [len(twice) - 1]])
+    plain, dup = cells.reference(TINY).logits_at(
+        TINY, 1, [prompt, twice], [[len(prompt) - 1], [len(twice) - 1]])
     return check.gaps(dup, [tok])[0] < check.gaps(plain, [tok])[0]
 
 
@@ -116,18 +124,18 @@ def token_altered(engine):
     engine.step = altered
 
 
-def _run(tamper, keep=None, control=False):
+def _run(tamper, conf, keep=None, control=False):
     return harness.run(
-        config_name="tiny-moe", conf=TINY,
+        config_name="tiny-" + conf["model_type"], conf=conf,
         traffic_spec=TRAFFIC, seed=SEED, seconds=3.0, trace=False,
         metrics=[], t_start=time.perf_counter(),
         devices=jax.devices(), tamper=tamper, control=control, keep=keep)
 
 
-@pytest.fixture(scope="module")
-def sound():
+@pytest.fixture(scope="module", params=ARCHS)
+def sound(request):
     keep: dict = {}
-    return _run(fix_first_decode, keep), keep
+    return _run(fix_first_decode, request.param, keep), keep
 
 
 def test_sound_run_is_correct(sound):
@@ -138,15 +146,17 @@ def test_sound_run_is_correct(sound):
     assert res["attempted"] > len(keep["sample"]) > 0
 
 
+@pytest.mark.parametrize("conf", ARCHS)
 @pytest.mark.parametrize("fault", [state_unchanged, token_altered],
                          ids=["state_unchanged", "token_altered"])
-def test_fault_makes_run_incorrect(fault):
-    res = _run(fault)
+def test_fault_makes_run_incorrect(fault, conf):
+    res = _run(fault, conf)
     assert res["correct"] is False
     assert res["checks"]["served_logit_gap"]["value"] > LIMIT
 
 
-def test_control_reads_above_the_limit():
-    res = _run(fix_first_decode, control=True)
+@pytest.mark.parametrize("conf", ARCHS)
+def test_control_reads_above_the_limit(conf):
+    res = _run(fix_first_decode, conf, control=True)
     assert res["correct"] is False
     assert res["checks"]["served_logit_gap"]["value"] > LIMIT

@@ -14,6 +14,7 @@ HERE = Path(__file__).resolve().parents[1]
 REPO = HERE.parent
 sys.path.insert(0, str(HERE))
 
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 import cells  # noqa: E402
@@ -66,6 +67,135 @@ def test_new_cell_and_metric_are_taken_without_edits(tmp_path, monkeypatch):
     class Ctx:
         reqs = [1, 2, 3]
     assert cells.metric_reader("new.count")(Ctx) == 3.0
+
+
+TOY_REFERENCE = '''"""Toy architecture: Llama's decoder with its leaves
+under other names, and one more norm before the output head."""
+
+import numpy as np
+
+import weights as W
+from reference import llama as L
+
+NAMES = {"attn_norm": "pre_attn", "wq": "q", "wk": "k", "wv": "v",
+         "wo": "o", "mlp_norm": "pre_mlp", "w_gate": "gate", "w_up": "up",
+         "w_down": "down"}
+layer_kinds = L.layer_kinds
+token_ops = L.token_ops
+paged_attention = L.paged_attention
+
+
+def global_leaves(conf):
+    g = L.global_leaves(conf)
+    return {"tokens": g["embed"], "out_norm": g["final_norm"],
+            "head_norm": g["final_norm"], "head": g["lm_head"]}
+
+
+def layer_leaves(conf, kind):
+    return {NAMES[n]: s for n, s in L.layer_leaves(conf, kind).items()}
+
+
+def logits_at(conf, seed, seqs, rows, quant=None):
+    key = W.seed_key(seed)
+    g = W.global_weights(key, global_leaves(conf))
+    eps = conf.get("rms_norm_eps", 1e-6)
+    out = []
+    for seq, r in zip(seqs, rows):
+        x = g["tokens"][np.asarray(seq)].astype(L.F32)
+        for layer, kind in enumerate(layer_kinds(conf)):
+            drawn = W.layer_weights(key, layer_leaves(conf, kind), layer)
+            w = {n: drawn[t] for n, t in NAMES.items()}
+            x = x + L.attention(L.rmsnorm(x, w["attn_norm"], eps), w, conf,
+                                quant)
+            x = x + L.dense_mlp(L.rmsnorm(x, w["mlp_norm"], eps), w, conf,
+                                kind, quant)
+        h = L.rmsnorm(L.rmsnorm(x, g["out_norm"], eps), g["head_norm"], eps)
+        out.append(np.asarray(L.mm("td,dv->tv", h[np.asarray(r)], g["head"],
+                                   quant)))
+    return out
+'''
+TOY_PROGRAM = '''"""Toy architecture on the program's dense path (its extra
+norm has no place there and is left out)."""
+
+from programs import llama as P
+
+NAMES = {"attn_norm": "pre_attn", "wq": "q", "wk": "k", "wv": "v",
+         "wo": "o", "mlp_norm": "pre_mlp", "w_gate": "gate", "w_up": "up",
+         "w_down": "down"}
+model_config = P.model_config
+
+
+def global_tree(g, cfg):
+    return P.global_tree({"embed": g["tokens"], "final_norm": g["out_norm"],
+                          "lm_head": g["head"]}, cfg)
+
+
+def layer_tree(leaves, kind):
+    return P.layer_tree({n: leaves[t] for n, t in NAMES.items()}, kind)
+'''
+TOY_CONFIG = {"model_type": "toyarch", "hidden_size": 64,
+              "intermediate_size": 128, "num_attention_heads": 2,
+              "num_key_value_heads": 1, "head_dim": 32,
+              "num_hidden_layers": 2, "vocab_size": 1024,
+              "serving": {"page_size": 16, "rows": 2, "kv_pages": 16},
+              "check": {"served_logit_gap": 0.5}}
+
+
+def _copied_checkout(tmp_path, monkeypatch):
+    root = tmp_path / "checkout"
+    shutil.copytree(HERE, root / "perfbench",
+                    ignore=shutil.ignore_patterns("__pycache__", ".*"))
+    monkeypatch.setattr(cells, "HERE", root / "perfbench")
+    monkeypatch.setattr(cells, "REPO", root)
+    return root
+
+
+def test_new_architecture_is_taken_without_edits(tmp_path, monkeypatch):
+    bench = cells.load_benchmark()
+    root = _copied_checkout(tmp_path, monkeypatch)
+    pb = root / "perfbench"
+    (pb / "reference" / "toyarch.py").write_text(TOY_REFERENCE)
+    (pb / "programs" / "toyarch.py").write_text(TOY_PROGRAM)
+    (pb / "configs" / "toy.json").write_text(json.dumps(TOY_CONFIG))
+    bench["configs"].append({"name": "toy", "source": "x",
+                             "file": "perfbench/configs/toy.json",
+                             "reduced": [], "why": "x"})
+    bench["workloads"].append({"name": "toy-cell", "config": "toy",
+                               "traffic": "agent-shared", "chips": 1,
+                               "why": "x"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    import flops
+    import sut
+    import weights as W
+    b = cells.load_benchmark()
+    conf = cells.config(b, cells.workload(b, "toy-cell")["config"])
+    ref = cells.reference(conf)
+    assert Path(ref.__file__) == pb / "reference" / "toyarch.py"
+    assert Path(cells.program(conf).__file__) == \
+        pb / "programs" / "toyarch.py"
+    assert "head_norm" in ref.global_leaves(conf)
+    seq = np.arange(20, dtype=np.int32) * 7 % conf["vocab_size"]
+    (lg,) = ref.logits_at(conf, 3, [seq], [[0, 19]])
+    assert lg.shape == (2, conf["vocab_size"]) and np.isfinite(lg).all()
+    assert flops.token_ops(conf, 5, True) > 0
+    cfg = sut.model_config("toy", conf)
+    params = sut.program_params(conf, cfg, 3)      # checked against the
+    np.testing.assert_array_equal(                 # program's schema
+        np.asarray(params["blocks"][0][0]["attn"]["wq"][1]),
+        np.asarray(W.layer_weights(W.seed_key(3), ref.layer_leaves(
+            conf, "dense"), 1)["q"]))
+
+
+@pytest.mark.parametrize("look", ["reference", "program"])
+def test_an_architecture_without_its_module_is_named(tmp_path, monkeypatch,
+                                                     look):
+    _copied_checkout(tmp_path, monkeypatch)
+    conf = dict(TOY_CONFIG, model_type="nosucharch")
+    where = {"reference": "perfbench/reference/nosucharch.py",
+             "program": "perfbench/programs/nosucharch.py"}[look]
+    with pytest.raises(FileNotFoundError, match=where):
+        getattr(cells, look)(conf)
 
 
 def _run(cwd, env_extra=None):

@@ -9,6 +9,13 @@ suffix prefill of 8 tokens -- and decodes 3 steps, inside one
 metrics that read those spans are given a stand-in for the harness's
 context; on the older fixture, recorded from a program without the
 spans, each finds nothing.
+
+The decode and prefill programs and the paged decode-attention kernel
+are found by name (``jit__paged_decode_step``, ...,
+``paged_decode_attention``); on the newer fixture that finds what the
+older lookup by the kernel's first operand found.  The older fixture's
+programs and kernels have no names (``jit__unknown``, ``%closed_call``),
+so there the readers find nothing.
 """
 
 from __future__ import annotations
@@ -25,6 +32,7 @@ sys.path.insert(0, str(HERE))
 import pytest  # noqa: E402
 
 import cells  # noqa: E402
+import flops  # noqa: E402
 import spans as S  # noqa: E402
 import trace_reduce as T  # noqa: E402
 
@@ -32,12 +40,32 @@ DATA = HERE / "tests" / "data"
 NP, SUFFIX, STEPS = 4, 8, 3
 READERS = ("step.suffix_ms_per_token", "engine.admit_wait_ms",
            "fleet.host_ms_per_step")
+NAMED = ("step.decode_ms", "step.prefill_ms_per_req",
+         "paged_decode_attention_roofline")
+# the fixtures' engine (record_spans_fixture.py): 16-token pages, a
+# 64-token budget; one row's 24-token prompt, 16 of it cached, 3 steps
+FIXTURE_CONF = {"model_type": "llama", "hidden_size": 256,
+                "intermediate_size": 512, "num_attention_heads": 2,
+                "num_key_value_heads": 2, "head_dim": 128,
+                "num_hidden_layers": 2, "vocab_size": 1024}
 
 
 @dataclass
 class Ctx:
     trace: object
     trace_span: tuple
+    conf: dict = None
+    max_len: int = 64
+    serving: dict = None
+    decodes: tuple = ((0, [25]), (0, [26]), (0, [27]))
+    admissions: tuple = ((0, 16 + SUFFIX, 16),)
+
+    def __post_init__(self):
+        self.conf = self.conf or FIXTURE_CONF
+        self.serving = self.serving or {"page_size": 16}
+
+    def peak(self):
+        return flops.peak("TPU v5 lite")
 
 
 @pytest.fixture(scope="module")
@@ -131,3 +159,45 @@ def test_a_trace_without_the_spans_reads_nothing(name):
     tr = T.load(str(DATA / "paged_decode.xplane.pb"))
     assert cells.metric_reader(name)(Ctx(tr, tr.span("bench.window"))) \
         is None
+
+
+def by_operand(name):
+    """The reader as it found its programs and kernel before they had
+    names: by the Pallas call's first operand (the page table ``s32[``,
+    one row's or wider; activations ``bf16[`` for the cold prefill)."""
+    read = cells.metric_reader(name)
+    g = read.__globals__
+    paged, flash = T.pallas_call("s32["), T.pallas_call("bf16[")
+
+    def lookup(ctx):
+        np_pages = ctx.max_len // ctx.serving["page_size"]
+        if name == "paged_decode_attention_roofline":
+            g["KERNEL"] = paged
+            return read(ctx)
+        if name == "step.decode_ms":
+            mods = T.modules_with(ctx.trace, lambda op: paged(op)
+                                  and T.operand_size(op) > np_pages)
+            key = "PROGRAM"
+        else:
+            mods = T.modules_with(ctx.trace, lambda op: flash(op) or (
+                paged(op) and T.operand_size(op) == np_pages))
+            key = "PROGRAMS"
+        g[key] = lambda op: op.module in mods
+        return read(ctx)
+    return lookup
+
+
+@pytest.mark.parametrize("name", NAMED)
+def test_programs_and_kernel_found_by_name_as_by_operand(ctx, name):
+    c = Ctx(ctx.trace, ctx.trace_span)
+    got = cells.metric_reader(name)(c)
+    assert got is not None and got > 0
+    assert got == by_operand(name)(c)
+
+
+@pytest.mark.parametrize("name", NAMED)
+def test_a_trace_without_program_names_reads_nothing(name):
+    tr = T.load(str(DATA / "paged_decode.xplane.pb"))
+    c = Ctx(tr, tr.span("bench.window"))
+    assert by_operand(name)(c) > 0
+    assert cells.metric_reader(name)(c) is None

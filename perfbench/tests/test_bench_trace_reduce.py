@@ -85,3 +85,17 @@ def test_operand_parsing():
     assert T.pallas_call("s32[")(op) and not T.pallas_call("bf16[")(op)
     assert T.operand_size(op) == 2304
     assert T.short_name(op) == "%closed_call.3 tpu_custom_call"
+
+
+def test_programs_and_kernels_by_name():
+    op = T.Op("%paged_decode_attention.9 = bf16[2,2,1,128] custom-call("
+              "s32[8]{0} %a), custom_call_target=\"tpu_custom_call\"",
+              "jit__paged_decode_step(13756119872087562979)", 0, 1, {})
+    assert T.kernel("paged_decode_attention")(op)
+    assert not T.kernel("decode_attention")(op)
+    assert T.in_program("jit__paged_decode_step")(op)
+    assert T.in_program("jit__paged_prefill", "jit__paged_decode_step")(op)
+    assert not T.in_program("jit__paged_prefill")(op)
+    fusion = T.Op("%paged_decode_attention.9 = bf16[2] fusion(%a)",
+                  "jit__paged_decode_step(1)", 0, 1, {})
+    assert not T.kernel("paged_decode_attention")(fusion)

@@ -164,6 +164,27 @@ def modules_with(tr: Trace, pred) -> set:
             if pred(op)}
 
 
+def in_program(*names: str):
+    """Predicate: an op of one of the programs (XLA modules) called
+    ``names``, a jitted function's ``jit_<name>``; the trace adds the
+    program's fingerprint in parentheses: ``jit__paged_decode_step(13...)``.
+    """
+    def pred(op: Op) -> bool:
+        return op.module.split("(", 1)[0] in names
+    return pred
+
+
+def kernel(name: str):
+    """Predicate: a Pallas kernel (``tpu_custom_call``) given ``name``
+    (``pl.pallas_call(..., name=...)``): the trace names the op after it,
+    ``%paged_decode_attention.9 = ...``."""
+    def pred(op: Op) -> bool:
+        head = op.name.split(" = ", 1)[0].lstrip("%")
+        return ("tpu_custom_call" in op.name
+                and head.rsplit(".", 1)[0] == name)
+    return pred
+
+
 def pallas_call(first_operand: str):
     """Predicate: a Pallas kernel (``tpu_custom_call``) whose first
     operand's type starts with ``first_operand``, e.g. ``"s32["`` for a
